@@ -21,6 +21,7 @@ exactly.
 
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass, field
 
@@ -48,6 +49,18 @@ _VERDICT_TO_STATUS = {
 
 
 _REPORTED = [int(v) for v in _VERDICT_TO_STATUS]
+
+
+@contextlib.contextmanager
+def _timed(stage_s: dict, key: str, span):
+    """Add the block's host seconds to ``stage_s[key]``, inside ``span``
+    (a recorder span), so the span and the stage share one boundary."""
+    with span:
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            stage_s[key] += time.perf_counter() - t0
 
 
 def _row_responses(resource: dict, verdict_of, rule_refs,
@@ -300,7 +313,11 @@ class BackgroundScanner:
 
     def _scan(self, resources, rec, tr) -> ScanResult:
         start = time.monotonic()
-        resources = resources if resources is not None else self.snapshot()
+        if resources is None:
+            with rec.span(tr, "scan_snapshot") as span:
+                resources = self.snapshot()
+                if span is not None:
+                    span.label(rows=len(resources))
         if tr is not None:
             tr.labels["resources"] = len(resources)
         result = ScanResult(resources_scanned=len(resources))
@@ -313,7 +330,7 @@ class BackgroundScanner:
                                "cols": {}}
             return result
 
-        memos = None
+        flattened = None
         e0 = time.perf_counter()
         if self.mesh is not None:
             from ..parallel import sharded_scan
@@ -324,9 +341,11 @@ class BackgroundScanner:
             verdicts, _, _ = sharded_scan(src, resources, self.mesh)
             scan_lane = "mesh"
         elif self._inc is not None:
-            # flatten chunk-wise and keep the split rows: the same single
-            # flatten both scores this pass and seeds the delta state
-            verdicts, memos = self._scan_rows(resources, result.stage_s)
+            # flatten chunk-wise and keep the packed batches: the same
+            # single flatten both scores this pass and seeds the delta
+            # state
+            verdicts, flattened = self._scan_rows(resources,
+                                                  result.stage_s, rec, tr)
             scan_lane = "incremental"
         else:
             from ..models.flatten import pipeline_enabled
@@ -353,44 +372,39 @@ class BackgroundScanner:
 
         # counts straight from the matrix; the per-resource responses
         # are built only when a consumer (the report pipeline) reads them
-        verdicts = np.asarray(verdicts)
-        cols = verdicts[:, [ref.rule_index for ref in self.cps.rule_refs]]
-        result.rules_evaluated = int(np.isin(cols, _REPORTED).sum())
-        result.violations = int((cols == Verdict.FAIL).sum())
+        with rec.span(tr, "scan_tally"):
+            verdicts = np.asarray(verdicts)
+            cols = verdicts[:, [ref.rule_index
+                                for ref in self.cps.rule_refs]]
+            result.rules_evaluated = int(np.isin(cols, _REPORTED).sum())
+            result.violations = int((cols == Verdict.FAIL).sum())
         result.verdicts = verdicts
         result.rule_refs = list(self.cps.rule_refs)
         result.resources = resources
 
-        if memos is not None:
-            keys = [self._res_key(r) for r in resources]
-            self._state = {
-                "keys": keys,
-                "resources": dict(zip(keys, resources)),
-                "memos": memos,
-                "cols": {(ref.policy.name, ref.rule.name):
-                         verdicts[:, ref.rule_index].astype(np.int8)
-                         for ref in self.cps.rule_refs},
-            }
+        if flattened is not None:
+            with rec.span(tr, "scan_persist"):
+                self._state = self._persist(resources, flattened, verdicts)
 
         if self.report_gen is not None:
-            r0 = time.perf_counter()
-            self.report_gen.add(*result.responses)
-            rec.add_span(tr, "scan_responses", r0, time.perf_counter(),
-                         violations=result.violations)
+            with rec.span(tr, "scan_responses",
+                          violations=result.violations):
+                self.report_gen.add(*result.responses)
         result.duration_s = time.monotonic() - start
         return result
 
-    def _scan_rows(self, resources: list[dict], stage_s: dict):
-        """Chunked flatten + device eval that also returns the split
-        flatten rows as epoch-stamped memos (one flatten serves both).
+    def _scan_rows(self, resources: list[dict], stage_s: dict, rec, tr):
+        """Chunked flatten + device eval that also returns each chunk's
+        packed batch, stamped with the dictionary it was flattened
+        against, for :meth:`_persist` (one flatten serves both).
 
         Host-lane cells resolve per chunk — prefetch dispatched before
         the blocking device eval, memoized post-pass after — so the
         incremental scan reports precondition/variable rules exactly
         like the full-scan paths instead of dropping them, and repeat
         scans of unchanged bodies answer from the host-verdict memo.
-        ``stage_s`` accumulates host seconds per stage."""
-        from ..models.flatten import MemoRow, split_packed_rows
+        ``stage_s`` accumulates host seconds per stage; each stage is
+        also a span (flatten, device_dispatch, host_resolve) on ``tr``."""
         from ..parallel.mesh import DEFAULT_CHUNK
         from .hostlane import resolver
 
@@ -400,28 +414,50 @@ class BackgroundScanner:
         for k in ("flatten", "device", "host_resolve"):
             stage_s.setdefault(k, 0.0)
         chunks = []
-        memos: dict[tuple, object] = {}
+        flattened = []
         for i in range(0, len(resources), DEFAULT_CHUNK):
             chunk = resources[i:i + DEFAULT_CHUNK]
-            t0 = time.perf_counter()
-            batch = self.cps.flatten_packed(chunk)
-            t1 = time.perf_counter()
-            pf = resolver().prefetch(self.cps, chunk) if has_host else None
-            t2 = time.perf_counter()
-            v = np.asarray(self.cps.evaluate_device(batch))
-            t3 = time.perf_counter()
-            if pf is not None or (v == int(Verdict.HOST)).any():
-                v = self.cps.resolve_host_cells(chunk, v, prefetch=pf)
-            t4 = time.perf_counter()
-            stage_s["flatten"] += t1 - t0
-            stage_s["device"] += t3 - t2
-            stage_s["host_resolve"] += (t2 - t1) + (t4 - t3)
+            with _timed(stage_s, "flatten",
+                        rec.span(tr, "flatten", rows=len(chunk))):
+                batch = self.cps.flatten_packed(chunk)
+            pf = None
+            if has_host:
+                with _timed(stage_s, "host_resolve",
+                            rec.span(tr, "host_resolve", lane="prefetch")):
+                    pf = resolver().prefetch(self.cps, chunk)
+            with _timed(stage_s, "device",
+                        rec.span(tr, "device_dispatch", rows=len(chunk))):
+                v = np.asarray(self.cps.evaluate_device(batch))
+            with _timed(stage_s, "host_resolve",
+                        rec.span(tr, "host_resolve", lane="post_pass")):
+                if pf is not None or (v == int(Verdict.HOST)).any():
+                    v = self.cps.resolve_host_cells(chunk, v, prefetch=pf)
             chunks.append(v)
+            flattened.append((chunk, batch, tensors.n_paths,
+                              tensors.dict_epoch))
+        return np.concatenate(chunks), flattened
+
+    def _persist(self, resources: list[dict], flattened: list,
+                 verdicts: np.ndarray) -> dict:
+        """The delta state a full pass seeds: row keys in scan order,
+        bodies, each row split from its chunk's batch as a memo stamped
+        with that chunk's dictionary, and the verdict columns."""
+        from ..models.flatten import MemoRow, split_packed_rows
+
+        memos: dict[tuple, MemoRow] = {}
+        for chunk, batch, n_paths, epoch in flattened:
             for r, row in zip(chunk, split_packed_rows(batch)):
-                memos[self._res_key(r)] = MemoRow(
-                    row=row, n_paths=tensors.n_paths,
-                    epoch=tensors.dict_epoch)
-        return np.concatenate(chunks), memos
+                memos[self._res_key(r)] = MemoRow(row=row, n_paths=n_paths,
+                                                  epoch=epoch)
+        keys = [self._res_key(r) for r in resources]
+        return {
+            "keys": keys,
+            "resources": dict(zip(keys, resources)),
+            "memos": memos,
+            "cols": {(ref.policy.name, ref.rule.name):
+                     verdicts[:, ref.rule_index].astype(np.int8)
+                     for ref in self.cps.rule_refs},
+        }
 
     # -------------------------------------------------------- delta scan
 

@@ -1,7 +1,9 @@
 """Prometheus-format metrics registry.
 
-Mirrors /root/reference/pkg/metrics/metrics.go:43-100 — the same six
-vectors with the same names — exposed in text format on /metrics
+Mirrors Kyverno's pkg/metrics/metrics.go:43-100 — the four
+vectors the engine writes (policy results, policy changes, admission
+review duration, admission requests) under the reference's names —
+exposed in text format on /metrics
 (prometheus_client is not baked into the image, so the exposition is
 implemented directly; the format is the stable text/plain 0.0.4 protocol).
 A periodic reset clears the registry like PromConfig's cron (metrics.go:17).
@@ -15,15 +17,6 @@ import threading
 import time
 
 from . import featureplane
-
-METRIC_NAMES = (
-    "kyverno_policy_results_total",
-    "kyverno_policy_rule_info_total",
-    "kyverno_policy_changes_total",
-    "kyverno_policy_execution_duration_seconds",
-    "kyverno_admission_review_duration_seconds",
-    "kyverno_admission_requests_total",
-)
 
 # default cumulative-bucket ladder for latency histograms (seconds):
 # spans the sub-ms device dispatch through the 10s webhook deadline so
@@ -154,21 +147,34 @@ class MetricsRegistry:
             inner = f"{inner},{extra}" if inner else extra
         return "{" + inner + "}"
 
-    def expose(self) -> str:
+    def families(self) -> set[str]:
+        """Names of every metric family the registry holds."""
+        with self._lock:
+            return set(self._counters) | set(self._gauges) | \
+                set(self._histograms)
+
+    def expose(self, skip: set | frozenset = frozenset()) -> str:
         """text/plain 0.0.4 exposition: counters, gauges, and real
         histograms (cumulative ``_bucket`` lines with ``le=`` labels plus
-        ``+Inf``, then ``_sum``/``_count``)."""
+        ``+Inf``, then ``_sum``/``_count``). Families named in ``skip``
+        are left out."""
         lines = []
         with self._lock:
             for name, series in sorted(self._counters.items()):
+                if name in skip:
+                    continue
                 lines.append(f"# TYPE {name} counter")
                 for key, value in series.items():
                     lines.append(f"{name}{self._fmt_labels(key)} {value:g}")
             for name, series in sorted(self._gauges.items()):
+                if name in skip:
+                    continue
                 lines.append(f"# TYPE {name} gauge")
                 for key, value in series.items():
                     lines.append(f"{name}{self._fmt_labels(key)} {value:g}")
             for name, series in sorted(self._histograms.items()):
+                if name in skip:
+                    continue
                 lines.append(f"# TYPE {name} histogram")
                 bounds = self._buckets.get(name, DEFAULT_LATENCY_BUCKETS)
                 for key, (count, total, per_bucket) in series.items():
@@ -284,24 +290,10 @@ def record_policy_results(registry: MetricsRegistry, policy: str, rule: str,
     })
 
 
-def record_policy_rule_info(registry: MetricsRegistry, policy: str, rule: str,
-                            rule_type: str, active: bool) -> None:
-    registry.set_gauge("kyverno_policy_rule_info_total", {
-        "policy_name": policy, "rule_name": rule, "rule_type": rule_type,
-    }, 1.0 if active else 0.0)
-
-
 def record_policy_change(registry: MetricsRegistry, policy: str, change: str) -> None:
     registry.inc_counter("kyverno_policy_changes_total", {
         "policy_name": policy, "policy_change_type": change,
     })
-
-
-def record_policy_execution_duration(registry: MetricsRegistry, policy: str,
-                                     rule: str, seconds: float) -> None:
-    registry.observe("kyverno_policy_execution_duration_seconds", {
-        "policy_name": policy, "rule_name": rule,
-    }, seconds)
 
 
 def record_admission_review_duration(registry: MetricsRegistry, operation: str,

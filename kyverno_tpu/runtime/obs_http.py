@@ -9,7 +9,9 @@ kube-apiserver-facing port also answers scrapes) and a standalone
 ``/metrics``
     Prometheus text 0.0.4 exposition from the metrics registry —
     including the ``kyverno_stage_duration_seconds`` bucket histograms
-    the trace recorder feeds, so per-stage p50/p99 are scrapeable.
+    the trace recorder feeds, so per-stage p50/p99 are scrapeable. A
+    front door with its own registry (the webhook's) serves that
+    registry plus the process-wide one's other families.
 ``/healthz``
     JSON liveness snapshot: ``ok``/``degraded`` status (the SLO
     watchdog's verdict), trace-recorder counters, the kill-switch lane
@@ -66,6 +68,18 @@ def _stream_enabled() -> bool:
         return False
 
 
+def _exposition(registry) -> str:
+    """``registry``'s exposition followed by the process-wide registry's
+    families it does not hold: the stage-span, trace and other
+    process-wide histograms are fed to ``metrics.registry()``, so a
+    front door with a registry of its own (the webhook's) serves both.
+    A family both hold is served once, from ``registry``."""
+    shared = metrics_mod.registry()
+    if registry is None or registry is shared:
+        return shared.expose()
+    return registry.expose() + shared.expose(skip=registry.families())
+
+
 def handle_obs_get(path: str, registry=None):
     """Route one GET. Returns ``(status, body_bytes, content_type)`` or
     ``None`` when ``path`` is not an observability endpoint (the caller
@@ -80,8 +94,8 @@ def handle_obs_get(path: str, registry=None):
     if route == "/metrics":
         # settle the recorder's deferred histogram feed before exposing
         tracing.recorder().feed_metrics()
-        reg = registry if registry is not None else metrics_mod.registry()
-        return 200, reg.expose().encode(), "text/plain; version=0.0.4"
+        return 200, _exposition(registry).encode(), \
+            "text/plain; version=0.0.4"
     if route == "/healthz":
         rec = tracing.recorder()
         rec.feed_metrics()

@@ -36,14 +36,31 @@ served by ``/debug/traces`` (runtime/obs_http.py). Stage latencies feed
 ``kyverno_stage_duration_seconds`` bucket histograms in the metrics
 registry at finish() time, which is where /metrics p50/p99 per stage
 come from.
+
+Two more views of the same spans:
+
+- **Garbage collection.** One ``gc.callbacks`` hook charges every
+  collection that runs on a thread with a bound trace to that trace's
+  ``gc_s`` / ``gc_collections`` tally (not one span per collection: a
+  scan pass of deep copies triggers over a thousand). The tally settles
+  as one ``stage="gc"`` observation per trace.
+- **The device profiler's clock.** While a ``jax.profiler`` session is
+  active, every context-managed span (and each collection) also opens a
+  ``TraceAnnotation("ktpu:<name>")``, so the spans sit on the profiler's
+  host timeline beside the device ops. With no session this costs one
+  ``is_enabled()`` check; a process that never imported jax holds no
+  session and pays a dict lookup. Explicit-timestamp spans
+  (:meth:`TraceRecorder.add_span`) are not annotated.
 """
 
 from __future__ import annotations
 
 import contextlib
 import contextvars
+import gc
 import heapq
 import itertools
+import sys
 import threading
 import time
 from collections import deque
@@ -132,6 +149,29 @@ def _metrics():
     return _metrics_mod
 
 
+ANNOTATION_PREFIX = "ktpu:"
+
+_annotation_cls = None
+
+
+def _annotation(name: str):
+    """An unentered ``jax.profiler.TraceAnnotation("ktpu:<name>")`` while
+    a profiler session is active, else None. jax is looked up, never
+    imported: a process without it holds no profiler session (the
+    oracle-pool workers never import jax)."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation as cls
+
+        _annotation_cls = cls
+    if not cls.is_enabled():
+        return None
+    return cls(ANNOTATION_PREFIX + name)
+
+
 class Span:
     """One timed stage. Immutable once ``end`` has stamped ``t1``."""
 
@@ -167,7 +207,7 @@ class Trace:
 
     __slots__ = ("seq", "t_wall", "_trace_id", "kind", "t_start", "t_end",
                  "spans", "labels", "max_spans", "spans_dropped",
-                 "_finished")
+                 "gc_s", "gc_collections", "_finished")
 
     def __init__(self, kind: str, labels: dict, max_spans: int):
         # id parts captured now, rendered lazily — formatting is pure
@@ -182,6 +222,9 @@ class Trace:
         self.labels = labels
         self.max_spans = max_spans
         self.spans_dropped = 0
+        # garbage-collection pauses on a thread bound to this trace
+        self.gc_s = 0.0
+        self.gc_collections = 0
         self._finished = False
 
     @property
@@ -217,6 +260,8 @@ class Trace:
             "duration_us": int(self.duration_s * 1e6),
             "labels": {k: str(v) for k, v in self.labels.items()},
             "spans_dropped": self.spans_dropped,
+            "gc_us": int(self.gc_s * 1e6),
+            "gc_collections": self.gc_collections,
             "spans": [s.to_dict(self.t_start)
                       for s in sorted(self.spans, key=lambda s: s.t0)],
         }
@@ -238,7 +283,7 @@ _NOOP = _NoOpSpan()
 
 
 class _LiveSpan:
-    __slots__ = ("_trace", "_name", "_labels", "_t0")
+    __slots__ = ("_trace", "_name", "_labels", "_t0", "_ann")
 
     def __init__(self, trace: Trace, name: str, labels: dict | None):
         self._trace = trace
@@ -246,6 +291,9 @@ class _LiveSpan:
         self._labels = labels
 
     def __enter__(self):
+        ann = self._ann = _annotation(self._name)
+        if ann is not None:
+            ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -256,10 +304,55 @@ class _LiveSpan:
         self._labels.update(kv)
 
     def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         self._trace.add_span(Span(
-            self._name, self._t0, time.perf_counter(),
+            self._name, self._t0, t1,
             threading.current_thread().name, self._labels))
         return False
+
+
+# the collection in progress, if it interrupted a bound trace: (trace,
+# perf_counter at start, open annotation). Collections never overlap —
+# the interpreter runs one at a time — so one slot serves every thread.
+_gc_open: tuple | None = None
+_gc_hooked = False
+_gc_hook_lock = threading.Lock()
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: charge the pause to the interrupted
+    thread's bound trace, if it has one."""
+    global _gc_open
+    if phase == "start":
+        trace = _current.get()
+        if trace is None or trace._finished:
+            return
+        ann = _annotation("gc")
+        if ann is not None:
+            ann.__enter__()
+        _gc_open = (trace, time.perf_counter(), ann)
+        return
+    opened = _gc_open
+    if opened is None:
+        return
+    _gc_open = None
+    trace, t0, ann = opened
+    trace.gc_s += time.perf_counter() - t0
+    trace.gc_collections += 1
+    if ann is not None:
+        ann.__exit__(None, None, None)
+
+
+def _hook_gc() -> None:
+    """Install :func:`_on_gc` once per process, whatever the number of
+    recorders: the hook charges the bound trace, not a recorder."""
+    global _gc_hooked
+    with _gc_hook_lock:
+        if not _gc_hooked:
+            gc.callbacks.append(_on_gc)
+            _gc_hooked = True
 
 
 class TraceRecorder:
@@ -281,6 +374,7 @@ class TraceRecorder:
         # read time (scrape, export) or at the backstop bound
         self._pending_metrics: deque[Trace] = deque()
         self.stats = {"started": 0, "finished": 0, "dropped_unfinished": 0}
+        _hook_gc()
 
     # ------------------------------------------------------------ record
 
@@ -339,12 +433,13 @@ class TraceRecorder:
 
     def feed_metrics(self) -> None:
         """Settle every pending finished trace: admit it to the ring and
-        K-slowest heap and feed its spans into the per-stage latency
-        histograms (kyverno_stage_duration_seconds / traces_total).
-        Reads (scrape, export, /debug/traces) call this first, so the
-        deferral is invisible to consumers. Shared adopted spans observe
-        once — the _counted flag survives the span being queued under
-        several traces."""
+        K-slowest heap and feed its spans, and its garbage-collection
+        tally as one ``stage="gc"`` observation, into the per-stage
+        latency histograms (kyverno_stage_duration_seconds /
+        traces_total). Reads (scrape, export, /debug/traces) call this
+        first, so the deferral is invisible to consumers. Shared adopted
+        spans observe once — the _counted flag survives the span being
+        queued under several traces."""
         try:
             metrics_mod = _metrics()
             reg = metrics_mod.registry()
@@ -367,6 +462,8 @@ class TraceRecorder:
                 continue
             try:
                 metrics_mod.record_trace(reg, trace.kind, trace.duration_s)
+                metrics_mod.record_stage_duration(
+                    reg, "gc", trace.gc_s, kind=trace.kind)
                 for span in trace.spans:
                     if span._counted:
                         continue

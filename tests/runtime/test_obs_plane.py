@@ -416,3 +416,62 @@ class TestScrapeRace:
             "kyverno_stage_duration_seconds", {"stage": "scatter"})
         assert flat - before_flat == n_threads * n_traces
         assert scat - before_scat == n_threads * n_traces
+
+
+class TestWebhookMetrics:
+    def test_webhook_port_serves_stage_histograms(self):
+        """The webhook's /metrics serves its own registry and the
+        process-wide one the recorder feeds, each family once."""
+        import http.client
+
+        from kyverno_tpu.api.load import load_policy
+        from kyverno_tpu.runtime.client import FakeCluster
+        from kyverno_tpu.runtime.policycache import PolicyCache
+        from kyverno_tpu.runtime.webhook import (VALIDATING_WEBHOOK_PATH,
+                                                 WebhookServer)
+
+        cache = PolicyCache()
+        cache.add(load_policy({
+            "apiVersion": "kyverno.io/v1", "kind": "ClusterPolicy",
+            "metadata": {"name": "disallow-latest-tag"},
+            "spec": {"validationFailureAction": "enforce", "rules": [{
+                "name": "validate-image-tag",
+                "match": {"resources": {"kinds": ["Pod"]}},
+                "validate": {"message": "latest tag not allowed",
+                             "pattern": {"spec": {"containers": [
+                                 {"image": "!*:latest"}]}}}}]}}))
+        review = json.dumps({
+            "apiVersion": "admission.k8s.io/v1", "kind": "AdmissionReview",
+            "request": {"uid": "m-1", "kind": {"kind": "Pod"},
+                        "namespace": "default", "operation": "CREATE",
+                        "object": {"apiVersion": "v1", "kind": "Pod",
+                                   "metadata": {"name": "p",
+                                                "namespace": "default"},
+                                   "spec": {"containers": [
+                                       {"name": "c",
+                                        "image": "nginx:latest"}]}}}})
+        own = MetricsRegistry()
+        server = WebhookServer(policy_cache=cache, client=FakeCluster(),
+                               registry=own)
+        port = server.run(host="127.0.0.1", port=0).server_address[1]
+        conn = http.client.HTTPConnection("127.0.0.1", port, timeout=30)
+        try:
+            conn.request("POST", VALIDATING_WEBHOOK_PATH, review,
+                         {"Content-Type": "application/json"})
+            assert conn.getresponse().read()
+            conn.request("GET", "/metrics")
+            resp = conn.getresponse()
+            assert resp.status == 200
+            text = resp.read().decode()
+        finally:
+            conn.close()
+            server.stop()
+        assert own is not metrics_mod.registry()
+        assert "kyverno_admission_requests_total{" in text
+        assert 'kyverno_stage_duration_seconds_bucket{' in text
+        assert 'kyverno_trace_duration_seconds_count{kind="admission"}' \
+            in text
+        families = [line.split()[2] for line in text.splitlines()
+                    if line.startswith("# TYPE ")]
+        assert len(families) == len(set(families))
+        assert "kyverno_tpu_build_info" in families

@@ -6,13 +6,19 @@ switch), the MetricsRegistry bucket histograms + label escaping +
 build_info/reset gauges, and runtime/obs_http.py routing.
 """
 
+import gc
 import json
 import threading
 import time
 
+import numpy as np
+
 from kyverno_tpu.api.load import load_policy
+from kyverno_tpu.runtime import metrics as metrics_mod
 from kyverno_tpu.runtime import obs_http, tracing
+from kyverno_tpu.runtime.background import BackgroundScanner
 from kyverno_tpu.runtime.batch import CLEAN, AdmissionBatcher
+from kyverno_tpu.runtime.client import FakeCluster
 from kyverno_tpu.runtime.metrics import MetricsRegistry
 from kyverno_tpu.runtime.policycache import PolicyCache, PolicyType
 
@@ -402,3 +408,143 @@ class TestObsHttp:
         assert "traceEvents" in doc
         _, body, _ = obs_http.handle_obs_get("/debug/traces?n=bogus")
         assert len(json.loads(body)["traces"]) == 5   # bad n -> default
+
+
+# ----------------------------------------------------- scan stage spans
+
+SCAN_STAGES = ("scan_snapshot", "flatten", "device_dispatch",
+               "host_resolve", "scan_persist", "scan_tally")
+
+
+def scanner(n=12):
+    cluster = FakeCluster([pod("nginx:latest" if i % 3 == 0 else f"nginx:{i}",
+                               name=f"s{i}") for i in range(n)])
+    return BackgroundScanner([load_policy(ENFORCE)], client=cluster)
+
+
+def fake_annotation(enabled: bool):
+    """A stand-in for jax.profiler.TraceAnnotation that logs enter/exit
+    and answers ``is_enabled()`` as told."""
+
+    class Fake:
+        on = enabled
+        events: list = []
+
+        def __init__(self, name):
+            self.name = name
+
+        @classmethod
+        def is_enabled(cls):
+            return cls.on
+
+        def __enter__(self):
+            self.events.append(("enter", self.name))
+            return self
+
+        def __exit__(self, *exc):
+            self.events.append(("exit", self.name))
+            return False
+
+    return Fake
+
+
+class TestScanStageSpans:
+    def test_full_scan_records_each_stage_once(self):
+        rec = tracing.recorder()
+        rec.clear()
+        result = scanner().scan()
+        [t] = [t for t in rec.traces(8) if t.kind == "scan"]
+        names = [s.name for s in t.spans]
+        for stage in SCAN_STAGES:
+            assert names.count(stage) == 1, (stage, names)
+        by = {s.name: s for s in t.spans}
+        assert by["scan_snapshot"].labels["rows"] == 12
+        # the six are disjoint, in pass order, inside the trace
+        six = [by[n] for n in ("scan_snapshot", "flatten", "device_dispatch",
+                               "host_resolve", "scan_tally", "scan_persist")]
+        for a, b in zip(six, six[1:]):
+            assert a.t1 <= b.t0
+        assert t.t_start <= six[0].t0 and six[-1].t1 <= t.t_end
+        # the split rows are timed inside the persist stage
+        assert by["scan_persist"].t0 <= by["row_split"].t0
+        assert by["row_split"].t1 <= by["scan_persist"].t1
+        # ScanResult.stage_s times the same blocks, inside the spans
+        for stage, key in (("flatten", "flatten"),
+                           ("device_dispatch", "device"),
+                           ("host_resolve", "host_resolve")):
+            assert 0 < result.stage_s[key] <= by[stage].duration_s
+
+    def test_gc_tally_charged_to_the_bound_trace(self):
+        rec = tracing.TraceRecorder()
+        t = rec.start("scan")
+        assert (t.gc_s, t.gc_collections) == (0.0, 0)
+        with tracing.active(t):
+            gc.collect()
+        assert t.gc_collections >= 1 and t.gc_s > 0
+        charged = t.gc_collections
+        gc.collect()                  # no trace bound: charged to nobody
+        assert t.gc_collections == charged
+        rec.finish(t)
+        d = t.to_dict()
+        assert d["gc_collections"] == charged
+        assert d["gc_us"] == int(t.gc_s * 1e6)
+
+    def test_feed_metrics_observes_gc_once_per_trace(self):
+        reg = metrics_mod.registry()
+        labels = {"stage": "gc", "kind": "gc_probe"}
+        before = reg.histogram_count("kyverno_stage_duration_seconds",
+                                     labels)
+        rec = tracing.TraceRecorder()
+        for i in range(3):
+            t = rec.start("gc_probe")
+            if i:
+                with tracing.active(t):
+                    gc.collect()
+            rec.finish(t)
+        rec.feed_metrics()
+        rec.feed_metrics()
+        assert reg.histogram_count("kyverno_stage_duration_seconds",
+                                   labels) == before + 3
+
+    def test_span_annotates_only_under_a_profiler_session(self,
+                                                          monkeypatch):
+        fake = fake_annotation(enabled=False)
+        monkeypatch.setattr(tracing, "_annotation_cls", fake)
+        rec = tracing.TraceRecorder()
+        t = rec.start("scan")
+        with rec.span(t, "flatten"):
+            pass
+        with tracing.active(t):
+            gc.collect()
+        assert fake.events == []
+        fake.on = True
+        with rec.span(t, "flatten"):
+            assert fake.events == [("enter", "ktpu:flatten")]
+        assert fake.events[-1] == ("exit", "ktpu:flatten")
+        with tracing.active(t):
+            gc.collect()
+        assert ("enter", "ktpu:gc") in fake.events
+        assert fake.events[-1] == ("exit", "ktpu:gc")
+        # explicit-timestamp spans stay off the profiler timeline
+        n = len(fake.events)
+        rec.add_span(t, "scatter", t.t_start, time.perf_counter())
+        assert len(fake.events) == n
+        assert [s.name for s in t.spans] == ["flatten", "flatten", "scatter"]
+
+    def test_kill_switch_records_and_annotates_nothing(self, monkeypatch):
+        fake = fake_annotation(enabled=True)
+        monkeypatch.setattr(tracing, "_annotation_cls", fake)
+        rec = tracing.recorder()
+        rec.clear()
+        on = scanner().scan()
+        assert ("enter", "ktpu:scan_snapshot") in fake.events
+        fake.events.clear()
+        rec.clear()
+        monkeypatch.setenv("KTPU_TRACE", "0")
+        off = scanner().scan()
+        with tracing.active(None):
+            gc.collect()
+        assert rec.traces(100) == [] and fake.events == []
+        np.testing.assert_array_equal(on.verdicts, off.verdicts)
+        assert (on.violations, on.rules_evaluated) == \
+            (off.violations, off.rules_evaluated) and on.violations == 4
