@@ -562,42 +562,120 @@ def refresh_packed_row(memo: MemoRow, resource: dict,
     return MemoRow(row=row, n_paths=n_new, epoch=tensors.dict_epoch), True
 
 
+#: batches of fewer rows than this split row by row; from this many on
+#: the batched split is faster (PERF.md, "row split crossover")
+SPLIT_BATCHED_MIN_ROWS = 2
+#: cells (paths x slots) per block of the batched split: bounds its
+#: temporaries to about 2 MB whatever the batch's row count
+SPLIT_BLOCK_CELLS = 1 << 16
+
+
 def split_packed_rows(batch: PackedBatch) -> list[PackedRow]:
     """Decompose a freshly-flattened PackedBatch into per-resource rows.
 
     Per row the trailing all-zero slot columns are trimmed (zero fill is
     the dead encoding, so they are pure padding) and word0 string ids are
-    rebased through a per-row LUT onto a compact private table. The
-    inverse is splice_packed_rows; split→splice of every row reproduces
-    the batch's verdicts exactly (dictionary value lanes are pure
-    functions of the interned string and class-gated on read, so the
-    re-merged table can only differ in lanes the kernels never read)."""
+    rebased onto a compact private table, in ascending order of the
+    batch's ids. The inverse is splice_packed_rows; split→splice of every
+    row reproduces the batch's verdicts exactly (dictionary value lanes
+    are pure functions of the interned string and class-gated on read, so
+    the re-merged table can only differ in lanes the kernels never read).
+
+    Two lanes give identical rows. Below ``SPLIT_BATCHED_MIN_ROWS`` rows
+    (an admission's late arrival, a handful of cache misses) each row is
+    cut on its own (:func:`_split_row`). From there on the batch is cut
+    in blocks of rows with whole-array operations (:func:`_split_block`),
+    and a Python loop only slices each row out. Either way every row owns
+    its arrays: none is a view into the batch or a shared table, so a
+    memoized row pins and counts only its own bytes."""
     from ..runtime import tracing
 
     _t0 = time.perf_counter()
+    n = int(batch.n)
     cells, bmeta = np.asarray(batch.cells), np.asarray(batch.bmeta)
     str_bytes, dictv = np.asarray(batch.str_bytes), np.asarray(batch.dictv)
     rows: list[PackedRow] = []
-    for b in range(int(batch.n)):
-        rc = cells[b]                             # [P, E, 2]
-        used = rc.any(axis=2).any(axis=0)         # [E] slot columns in use
-        e_row = int(np.max(np.nonzero(used)[0]) + 1) if used.any() else 0
-        rc = rc[:, :e_row, :]
-        w0 = rc[..., 0]
-        ids = np.unique(w0)
-        ids = (ids[ids > 0] - 1).astype(np.int64)
-        lut = np.zeros(int(dictv.shape[0]) + 1, dtype=np.uint32)
-        lut[ids + 1] = np.arange(1, len(ids) + 1, dtype=np.uint32)
-        rc = np.stack([lut[w0], rc[..., 1]], axis=-1)
-        rows.append(PackedRow(
-            cells=np.ascontiguousarray(rc),
-            bmeta=int(bmeta[b]),
-            str_bytes=np.ascontiguousarray(str_bytes[ids]),
-            dictv=np.ascontiguousarray(dictv[ids]),
-        ))
+    if n < SPLIT_BATCHED_MIN_ROWS:
+        lane = "per_row"
+        for b in range(n):
+            rows.append(_split_row(cells[b], int(bmeta[b]), str_bytes, dictv))
+    else:
+        lane = "batched"
+        _, P, E, _ = cells.shape
+        step = max(1, SPLIT_BLOCK_CELLS // max(1, P * E))
+        for b0 in range(0, n, step):
+            b1 = min(n, b0 + step)
+            rows += _split_block(cells[b0:b1], bmeta[b0:b1], str_bytes, dictv)
     tracing.recorder().add_span(
         tracing.current(), "row_split", _t0, time.perf_counter(),
-        rows=len(rows))
+        rows=len(rows), lane=lane)
+    return rows
+
+
+def _split_row(rc: np.ndarray, bmeta: int, str_bytes: np.ndarray,
+               dictv: np.ndarray) -> PackedRow:
+    """One row [P, E, 2] cut through a LUT the size of the dictionary."""
+    used = rc.any(axis=2).any(axis=0)         # [E] slot columns in use
+    e_row = int(np.max(np.nonzero(used)[0]) + 1) if used.any() else 0
+    rc = rc[:, :e_row, :]
+    w0 = rc[..., 0]
+    ids = np.unique(w0)
+    ids = (ids[ids > 0] - 1).astype(np.int64)
+    lut = np.zeros(int(dictv.shape[0]) + 1, dtype=np.uint32)
+    lut[ids + 1] = np.arange(1, len(ids) + 1, dtype=np.uint32)
+    rc = np.stack([lut[w0], rc[..., 1]], axis=-1)
+    return PackedRow(
+        cells=np.ascontiguousarray(rc),
+        bmeta=bmeta,
+        str_bytes=np.ascontiguousarray(str_bytes[ids]),
+        dictv=np.ascontiguousarray(dictv[ids]),
+    )
+
+
+def _split_block(cells: np.ndarray, bmeta: np.ndarray,
+                 str_bytes: np.ndarray, dictv: np.ndarray) -> list[PackedRow]:
+    """Rows [k, P, E, 2] cut together. Each row's word0 cells are sorted
+    once, keyed with their position; the first cell of each distinct
+    non-zero id gets the next local id, and the running count, scattered
+    back to the cells' positions, is every cell's rebased id. One gather
+    then takes all rows' string and dictionary rows at once."""
+    k, P, E, _ = cells.shape
+    w0, w1 = cells[..., 0], cells[..., 1]
+    # [k, E]: a slot column is in use where either word of any path is
+    # set (reduced over a contiguous axis, which numpy does fastest)
+    used = np.ascontiguousarray((w0 | w1).transpose(0, 2, 1)).max(
+        axis=2, initial=0) > 0
+    e_rows = np.where(used.any(axis=1), E - np.argmax(used[:, ::-1], axis=1),
+                      0)
+    n_cells = P * E
+    key = w0.astype(np.uint64).reshape(k, n_cells) << np.uint64(32)
+    key |= np.arange(n_cells, dtype=np.uint64)
+    key.sort(axis=1)
+    sorted_w0 = (key >> np.uint64(32)).astype(np.uint32)
+    first = sorted_w0 > 0
+    first[:, 1:] &= sorted_w0[:, 1:] != sorted_w0[:, :-1]
+    rank = np.cumsum(first, axis=1, dtype=np.uint32)
+    at = (key & np.uint64(0xFFFFFFFF)).astype(np.intp)
+    at += (np.arange(k, dtype=np.intp) * n_cells)[:, None]
+    local = np.empty(k * n_cells, dtype=np.uint32)
+    local[at.ravel()] = rank.ravel()
+    out = np.empty((k, P, E, 2), dtype=np.uint32)
+    out[..., 0] = local.reshape(k, P, E)
+    out[..., 1] = w1
+    ids = sorted_w0[first].astype(np.intp) - 1
+    sb, dv = str_bytes[ids], dictv[ids]
+    rows: list[PackedRow] = []
+    start = 0
+    for j, (e_row, bm, end) in enumerate(zip(
+            e_rows.tolist(), bmeta.tolist(),
+            np.cumsum(np.count_nonzero(first, axis=1)).tolist())):
+        rows.append(PackedRow(
+            cells=out[j, :, :e_row].copy(),
+            bmeta=bm,
+            str_bytes=sb[start:end].copy(),
+            dictv=dv[start:end].copy(),
+        ))
+        start = end
     return rows
 
 
