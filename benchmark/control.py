@@ -25,7 +25,6 @@ sys.path[:] = [str(ROOT)] + [p for p in sys.path
                              if Path(p or ".").resolve() != ROOT / "benchmark"]
 
 from benchmark import arrivals, clusters, spec  # noqa: E402
-from benchmark.reference import Library  # noqa: E402
 
 
 def admission_checks(cell: spec.Cell, seed: int, seconds: float) -> list:
@@ -35,6 +34,7 @@ def admission_checks(cell: spec.Cell, seed: int, seconds: float) -> list:
     docs = cell.policies()
     window = {"name": "window", "rate": tr["rate_rps"], "seconds": seconds,
               "salt": f"s{seed}", "order_seed": seed}
+    Library = cell.reference().Library
     control = Library(docs, control=cfg["control"])
     rows = []
     for due, review in arrivals.requests(window, cfg["cluster"]):
@@ -52,6 +52,7 @@ def scan_checks(cell: spec.Cell, seed: int, passes: int) -> list:
     params = cfg["cluster"]
     gen = clusters.generator(params["generator"])
     n = gen.size(params)
+    Library = cell.reference().Library
     control = Library(docs, control=cfg["control"])
     keys = [(r.policy, r.name) for r in control.rules]
     rng = random.Random(seed)

@@ -74,7 +74,7 @@ class RunData:
     program: dict = field(default_factory=dict)    # counter/span deltas
     profile: dict | None = None                    # reduced device trace
     traced_rows: list | None = None                # bodies of traced pass
-    patterns: list = field(default_factory=list)
+    read_paths: set = field(default_factory=set)   # Library.read_paths()
     n_rules: int = 0
     peaks: dict | None = None
     attempted: int = 0

@@ -1,9 +1,11 @@
 """Plain reference: Kyverno validate-pattern semantics, written out alone.
 
-This module decides what ``correct`` means for every cell. It imports
-nothing of the program under test and takes nothing the program made: it
-reads the policy documents of a configuration and the resource bodies the
-benchmark generated, and answers with Kyverno's rule statuses.
+This module decides what ``correct`` means for every cell whose
+configuration names no reference of its own (``benchmark/spec.py`` gives
+the interface a reference keeps). It imports nothing of the program
+under test and takes nothing the program made: it reads the policy
+documents of a configuration and the resource bodies the benchmark
+generated, and answers with Kyverno's rule statuses.
 
 It covers what the configurations under ``benchmark/configs`` use, as
 Kyverno release-1.5 defines it, and refuses anything else loudly
@@ -37,6 +39,8 @@ from __future__ import annotations
 import copy
 import json
 import re
+
+from benchmark import roofline
 
 PASS, FAIL, SKIP, ERROR = "pass", "fail", "skip", "error"
 
@@ -389,6 +393,10 @@ class Library:
             for i in members:
                 out[i] = s
         return out
+
+    def read_paths(self) -> set:
+        """The paths the rules' patterns read (leaves and variables)."""
+        return roofline.read_paths([r.pattern for r in self.rules])
 
     def denials(self, request: dict) -> set:
         """(policy, rule) pairs of enforce policies that block one

@@ -1,12 +1,14 @@
 """The least bytes a policy scan has to move, counted from the inputs.
 
 A scan must at least read, for every row, the leaf values at the paths
-its policy set reads (pattern leaves, and the paths that pattern
-variables name), and write one verdict byte per (row, rule). Counting
-from the corpus and the policy set, not from the program's tensors,
-keeps the number the same whatever a later change does to the packing.
-A string leaf counts its UTF-8 bytes, a number 8, a boolean 1, and a
-map or list standing at a read path (a presence check) 1.
+its policy set reads, and write one verdict byte per (row, rule). The
+configuration's reference says which paths its rules read
+(``Library.read_paths()``); for validate patterns :func:`read_paths`
+walks them (pattern leaves, and the paths that pattern variables name).
+Counting from the corpus and the policy set, not from the program's
+tensors, keeps the number the same whatever a later change does to the
+packing. A string leaf counts its UTF-8 bytes, a number 8, a boolean 1,
+and a map or list standing at a read path (a presence check) 1.
 """
 
 from __future__ import annotations
@@ -64,9 +66,8 @@ def _at(node, path: tuple) -> int:
     return 0
 
 
-def least_bytes(rows: list, patterns: list, n_rules: int) -> int:
-    """Bytes read at the read paths of every row, plus a verdict byte
-    per (row, rule)."""
-    paths = read_paths(patterns)
+def least_bytes(rows: list, paths, n_rules: int) -> int:
+    """Bytes read at ``paths`` of every row, plus a verdict byte per
+    (row, rule)."""
     return sum(_at(row, p) for row in rows for p in paths) \
         + len(rows) * n_rules

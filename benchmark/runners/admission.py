@@ -24,7 +24,6 @@ import time
 from benchmark import arrivals, harness, promtext, spec
 from benchmark.stats import percentile
 from benchmark.runners import common
-from benchmark.reference import Library
 
 
 @contextlib.contextmanager
@@ -145,7 +144,7 @@ def run(ctx: harness.Context) -> harness.RunData:
     data.requests = [requests_row(r, give_up) for r in rows]
     data.attempted = len(rows)
     data.failed = sum(not r["ok"] for r in data.requests)
-    data.checks = check(Library(docs), window, cfg, rows)
+    data.checks = check(cell.reference().Library(docs), window, cfg, rows)
     return data
 
 
@@ -196,10 +195,10 @@ def requests_row(r: list, give_up: float) -> dict:
             "give_up_s": give_up - r[0]}
 
 
-def check(library: Library, window: dict, cfg: dict,
-          rows: list) -> list:
-    """Every answer against the reference: ``allowed`` and the denying
-    (policy, rule) set must equal it; a request with no answer fails."""
+def check(library, window: dict, cfg: dict, rows: list) -> list:
+    """Every answer against the reference ``library``: ``allowed`` and
+    the denying (policy, rule) set must equal it; a request with no
+    answer fails."""
     wrong = unanswered = 0
     reqs = arrivals.requests(window, cfg["cluster"])
     for (_, review), row in zip(reqs, rows):

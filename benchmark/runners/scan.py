@@ -12,17 +12,24 @@ only a pass that does no work reaches). After each pass a sample of rows
 drawn from the seed is kept, and once the window has closed every
 sampled row's verdicts are compared with the plain reference on every
 rule, host-resolved cells included.
+
+What the runner keeps across passes (the samples, the traced pass's
+bodies) is kept pickled, as bytes the garbage collector does not track:
+kept as objects, it grew the heap pass by pass, and with it the
+threshold of the interpreter's full collections, so that early passes
+paid them and later ones did not, and a run's rate rested on how many
+passes fit the window.
 """
 
 from __future__ import annotations
 
 import gc
+import pickle
 import random
 import time
 
 from benchmark import clusters, harness
 from benchmark.runners import common
-from benchmark.reference import Library
 
 
 def _key(resource: dict) -> tuple:
@@ -74,7 +81,8 @@ def run(ctx: harness.Context) -> harness.RunData:
                       else v.name.lower()) for v in Verdict}
     rng = random.Random(ctx.seed)
     data = harness.RunData(kind="scan", seconds=0.0, setup_s=0.0)
-    samples: list = []
+    samples: list = []          # pickled (body, verdicts) pairs
+    traced_rows = None          # pickled bodies of the traced pass
     missing = 0
     spent = 0.0
     log_dir = None
@@ -100,7 +108,7 @@ def run(ctx: harness.Context) -> harness.RunData:
                             "gc_s": gc_clock.seconds - gc0})
         if traced_dir is not None:
             log_dir = traced_dir
-            data.traced_rows = result.resources
+            traced_rows = _keep(result.resources)
         keys = [_key(r) for r in result.resources]
         got = set(keys)
         missing += (len(expected - got) + len(got - expected)
@@ -111,11 +119,11 @@ def run(ctx: harness.Context) -> harness.RunData:
         for b in pick:
             kind, ns, name = keys[b]
             truth = scanner.client.get_resource("", kind, ns, name)
-            samples.append((truth, {
+            samples.append(_keep((truth, {
                 (ref.policy.name, ref.rule.name):
                     names.get(int(result.verdicts[b, ref.rule_index]),
                               "unknown")
-                for ref in result.rule_refs}))
+                for ref in result.rule_refs})))
         del result
     gc_clock.close()
     data.seconds = spent
@@ -127,15 +135,22 @@ def run(ctx: harness.Context) -> harness.RunData:
         host_lane=host)
     common.reap_children()
     data.profile = common.reduce_trace(log_dir)
+    if traced_rows is not None:
+        data.traced_rows = pickle.loads(traced_rows)
 
-    library = Library(docs)
-    data.patterns = [r.pattern for r in library.rules]
+    library = cell.reference().Library(docs)
+    data.read_paths = library.read_paths()
     data.n_rules = len(library.rules)
     data.peaks = _peaks(ctx.device_kind)
     data.attempted = sum(ps["rows"] for ps in data.passes)
     data.failed = missing
-    data.checks = check(library, samples, missing)
+    data.checks = check(library, [pickle.loads(s) for s in samples],
+                        missing)
     return data
+
+
+def _keep(obj) -> bytes:
+    return pickle.dumps(obj, pickle.HIGHEST_PROTOCOL)
 
 
 class _GcClock:
@@ -164,9 +179,10 @@ def _peaks(kind: str):
         return None
 
 
-def check(library: Library, samples: list, missing: int) -> list:
-    """Sampled rows against the reference on every rule; rows the pass
-    left out, added or doubled; rules one side has and the other not."""
+def check(library, samples: list, missing: int) -> list:
+    """Sampled rows against the reference ``library`` on every rule;
+    rows the pass left out, added or doubled; rules one side has and the
+    other not."""
     t = time.perf_counter()
     want_keys = set(library.index)
     wrong = 0

@@ -6,16 +6,39 @@ mix; each metric names a reader. They resolve to files in fixed places:
 - configuration ``<c>``: ``benchmark/configs/<c>.json``, whose
   ``policies`` list files beside it;
 - traffic mix ``<t>``: ``benchmark/traffic/<t>.json``;
-- metric ``<m>``: ``benchmark/metrics/<m>.py``, defining ``read(run)``.
+- metric ``<m>``: ``benchmark/metrics/<m>.py``, defining ``read(run)``;
+- plain reference ``<r>``, which decides ``correct``: the configuration's
+  optional ``"reference": "<r>"`` names ``benchmark/references/<r>.py``;
+  a configuration without the key is judged by ``benchmark/reference.py``.
 
-Adding a cell, configuration, traffic mix or metric is adding those files
-and entries; nothing here changes.
+A reference module imports nothing of the program (it may import helpers
+of ``benchmark/reference.py``, such as autogen expansion) and defines:
+
+- ``Library(docs, control=None)``: the rules of a configuration's policy
+  documents, with one of ``CONTROLS`` broken where ``control`` names it;
+  an instance has
+  - ``.rules``: items with ``.policy`` and ``.name``, in row order;
+  - ``.index``: ``(policy, rule) -> column`` of a row;
+  - ``.row(body, request=None)``: one status (``"pass"``, ``"fail"``,
+    ``"skip"``, ``"error"``) or ``None`` per rule;
+  - ``.denials(request)``: the ``(policy, rule)`` pairs that block one
+    admission request;
+  - ``.read_paths()``: the paths (tuples of keys, ``"[]"`` for a list's
+    elements) its rules read, which the roofline's least bytes count;
+- ``CONTROLS``: the controls it implements; the configuration's
+  ``control`` must be one of them;
+- ``Unsupported``: the exception it raises on a policy or value it does
+  not implement.
+
+Adding a cell, configuration, traffic mix, metric or reference is adding
+those files and entries; nothing here changes.
 """
 
 from __future__ import annotations
 
 import importlib.util
 import json
+import sys
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
@@ -48,6 +71,10 @@ def metric_path(name: str, here: Path = HERE) -> Path:
     return here / "metrics" / f"{name}.py"
 
 
+def reference_path(name: str, here: Path = HERE) -> Path:
+    return here / "references" / f"{name}.py"
+
+
 def _load_json(path: Path) -> dict:
     if not path.is_file():
         raise SpecError(f"missing {path}")
@@ -75,16 +102,57 @@ def load_policies(config: dict, here: Path = HERE) -> list[dict]:
     return docs
 
 
+def _load_file(path: Path, module_name: str, what: str):
+    if not path.is_file():
+        raise SpecError(f"missing {what} {path}")
+    mod_spec = importlib.util.spec_from_file_location(module_name, path)
+    module = importlib.util.module_from_spec(mod_spec)
+    # registered, so that dataclasses and pickling find the module
+    sys.modules[module_name] = module
+    mod_spec.loader.exec_module(module)
+    return module
+
+
+def _ident(name: str) -> str:
+    return name.replace(".", "_").replace("-", "_")
+
+
 def load_reader(name: str, here: Path = HERE):
     """The ``read`` function of metric ``name``'s reader file."""
-    path = metric_path(name, here)
-    if not path.is_file():
-        raise SpecError(f"missing metric reader {path}")
-    mod_spec = importlib.util.spec_from_file_location(
-        f"benchmark_metric_{name.replace('.', '_').replace('-', '_')}", path)
-    module = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(module)
-    return module.read
+    return _load_file(metric_path(name, here),
+                      f"benchmark_metric_{_ident(name)}",
+                      "metric reader").read
+
+
+def load_reference(config: dict, here: Path = HERE):
+    """The reference module that judges ``config``: the file its
+    ``reference`` key names, else ``benchmark/reference.py``. Refused
+    unless it defines the interface in this module's docstring and
+    implements the configuration's ``control``."""
+    if "reference" in config:
+        name = config["reference"]
+        module = _load_file(reference_path(name, here),
+                            f"benchmark_reference_{_ident(name)}",
+                            "reference")
+    else:
+        from benchmark import reference as module
+    where = getattr(module, "__file__", module.__name__)
+    library = getattr(module, "Library", None)
+    lacks = [m for m in ("row", "denials", "read_paths")
+             if not callable(getattr(library, m, None))] \
+        if isinstance(library, type) else ["Library"]
+    if not isinstance(getattr(module, "CONTROLS", None), (tuple, list)):
+        lacks.append("CONTROLS")
+    unsupported = getattr(module, "Unsupported", None)
+    if not (isinstance(unsupported, type)
+            and issubclass(unsupported, Exception)):
+        lacks.append("Unsupported")
+    if lacks:
+        raise SpecError(f"reference {where} lacks {lacks}")
+    if "control" in config and config["control"] not in module.CONTROLS:
+        raise SpecError(f"reference {where} has no control "
+                        f"{config['control']!r}; it has {module.CONTROLS}")
+    return module
 
 
 class Cell:
@@ -100,6 +168,7 @@ class Cell:
         self.config = _load_json(config_path(self.entry["config"], here))
         self.traffic = _load_json(traffic_path(self.entry["traffic"], here))
         self.here = here
+        self._reference = load_reference(self.config, here)
 
         def applies(metric: dict) -> bool:
             return "workloads" not in metric or name in metric["workloads"]
@@ -118,3 +187,7 @@ class Cell:
 
     def policies(self) -> list[dict]:
         return load_policies(self.config, self.here)
+
+    def reference(self):
+        """The reference module that decides this cell's ``correct``."""
+        return self._reference

@@ -1,6 +1,8 @@
-"""The benchmark's cells cut to sizes a CPU test can hold, and the
+"""The benchmark's cells cut to sizes a CPU test can hold, the
 admission cell that the harness serves but BENCHMARK.json does not hold
-yet (the program compiles inside its window; PERF.md, section 7)."""
+yet (the program compiles inside its window; PERF.md, section 7), and a
+toy plain reference with the interface ``spec.load_reference`` asks
+for."""
 
 import json
 import time
@@ -53,3 +55,28 @@ def run_cell(cell: spec.Cell, capsys, seconds: float, seed: int = 7,
     out = capsys.readouterr().out.strip().splitlines()
     assert rc == 0
     return json.loads(out[-1])
+
+
+TOY_REFERENCE = """
+CONTROLS = ("first_container_only",)
+
+
+class Unsupported(ValueError):
+    pass
+
+
+class Library:
+    TOY = True
+
+    def __init__(self, docs, control=None):
+        self.rules, self.index = [], {}
+
+    def row(self, body, request=None):
+        return []
+
+    def denials(self, request):
+        return set()
+
+    def read_paths(self):
+        return {("metadata", "name")}
+"""

@@ -102,7 +102,8 @@ def test_roofline_bytes_on_a_tiny_corpus():
     ]
     # row 1: images 7 + 3, name 3, one hostPath map 1, one number 8 = 22;
     # row 2: nothing; plus one verdict byte per (row, rule): 2 x 5
-    assert roofline.least_bytes(rows, patterns, 5) == 22 + 10
+    assert roofline.least_bytes(rows, roofline.read_paths(patterns), 5) \
+        == 22 + 10
 
 
 def test_roofline_reader_uses_the_named_program():
@@ -110,7 +111,7 @@ def test_roofline_reader_uses_the_named_program():
     rows = [{"metadata": {"name": "a" * 100}}]
     patterns = [{"metadata": {"name": "?*"}}]
     run = RunData(kind="scan", seconds=1, setup_s=1, traced_rows=rows,
-                  patterns=patterns, n_rules=10,
+                  read_paths=roofline.read_paths(patterns), n_rules=10,
                   peaks={"hbm_bytes_per_s": 1e9},
                   profile={"programs": {"jit_evaluate_blob": 1e-6,
                                         "jit_other": 5.0},

@@ -9,6 +9,7 @@ import shutil
 import pytest
 
 from benchmark import spec
+from benchmark.tests.cells import TOY_REFERENCE
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -73,14 +74,18 @@ def test_each_config_is_used_and_its_file_lies_under_paths(bench):
 
 
 def test_added_files_are_found_by_name(tmp_path, bench):
-    """A later change adds a cell with its own configuration, traffic
-    mix and metric reader: new files and entries, no edits."""
+    """A later change adds a cell with its own configuration, plain
+    reference, traffic mix and metric reader: new files and entries, no
+    edits."""
     here = tmp_path / "benchmark"
     shutil.copytree(spec.HERE / "configs", here / "configs")
     shutil.copytree(spec.HERE / "traffic", here / "traffic")
     shutil.copytree(spec.HERE / "metrics", here / "metrics")
+    (here / "references").mkdir()
+    (here / "references" / "toy-ref.py").write_text(TOY_REFERENCE)
     cfg = json.loads((here / "configs" / "best-practices.json").read_text())
     cfg["name"] = "tenant-copy"
+    cfg["reference"] = "toy-ref"
     (here / "configs" / "tenant-copy.json").write_text(json.dumps(cfg))
     (here / "traffic" / "admit-slow.json").write_text(json.dumps(
         {"kind": "admission", "rate_rps": 2.0, "warmup_s": 5,
@@ -103,6 +108,8 @@ def test_added_files_are_found_by_name(tmp_path, bench):
                                 "workloads": ["copy-admit-slow"]})
     cell = spec.Cell(added, "copy-admit-slow", here=here)
     assert cell.config["name"] == "tenant-copy"
+    assert cell.reference().Library.TOY
+    assert cell.reference().__file__ == str(here / "references" / "toy-ref.py")
     assert cell.traffic["rate_rps"] == 2.0
     readers = dict((m["name"], r) for m, r in cell.readers(trace=True))
     assert readers["admit.new_ms"](None) == 1.5
